@@ -79,13 +79,16 @@ TUNE_JSON = _ROOT / "BENCH_tune.json"
 def main() -> None:
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in SECTIONS.items():
         if only and name != only:
             continue
         try:
             emit(fn())
-        except Exception as e:  # pragma: no cover — keep the harness running
+        except Exception as e:
+            # Keep running the other sections; the exit code reports this one.
             print(f"{name}/ERROR,,{type(e).__name__}:{e}")
+            failed.append(name)
     # Persist the streamed-engine numbers so the perf trajectory is tracked
     # across PRs (written whenever the functional section ran).
     if paper_figs.LAST_STREAM_PAYLOAD is not None:
@@ -116,6 +119,8 @@ def main() -> None:
             json.dumps(paper_figs.LAST_TUNE_PAYLOAD, indent=2) + "\n"
         )
         print(f"# wrote {TUNE_JSON}", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -122,7 +122,7 @@ def dequantize_weights(q: QuantizedLinear) -> Array:
     return w_t.T
 
 
-def apply_linear(q, x: Array, *, interpret: bool = True) -> Array:
+def apply_linear(q, x: Array) -> Array:
     """``y = x @ W (+ bias)`` through the path selected by ``q.spec.mode``.
 
     ``x``: [..., K] activations; returns [..., F].  Accepts either a raw
@@ -133,7 +133,7 @@ def apply_linear(q, x: Array, *, interpret: bool = True) -> Array:
     from repro.core import prepared as _prepared
 
     if isinstance(q, _prepared.PreparedLinear):
-        return _prepared.apply_prepared(q, x, interpret=interpret)
+        return _prepared.apply_prepared(q, x)
     mode = q.spec.mode
     if mode == "dequant":
         y = _dequant_matmul(q, x)
@@ -151,7 +151,6 @@ def apply_linear(q, x: Array, *, interpret: bool = True) -> Array:
             bw=q.spec.bw,
             k=q.k,
             grid_kind=q.spec.w_kind,
-            interpret=interpret,
         ).reshape(x.shape[:-1] + (q.f,)).astype(x.dtype)
         # ^ kernel accumulates f32; cast back like every other mode so a
         #   bf16 model's residual stream keeps its dtype through the scan.

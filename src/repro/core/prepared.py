@@ -231,7 +231,7 @@ def stream_weights(pl: PreparedLinear) -> engine.StreamWeights:
     )
 
 
-def apply_prepared(pl: PreparedLinear, x: Array, *, interpret: bool = True) -> Array:
+def apply_prepared(pl: PreparedLinear, x: Array) -> Array:
     """``y = x @ W (+ bias)`` through the cached weight-stationary products.
 
     Bit-identical to ``apply_linear`` on the raw layer in every mode — only
@@ -254,7 +254,6 @@ def apply_prepared(pl: PreparedLinear, x: Array, *, interpret: bool = True) -> A
             bw=pl.spec.bw,
             k=pl.k,
             grid_kind=pl.spec.w_kind,
-            interpret=interpret,
         ).reshape(x.shape[:-1] + (pl.f,)).astype(x.dtype)
         # ^ kernel accumulates f32; cast back like every other mode so a
         #   bf16 model's residual stream keeps its dtype through the scan.

@@ -19,10 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax>=0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 
 Array = jax.Array
 
@@ -76,13 +73,17 @@ def pipeline_apply(
             outs = outs.at[idx].set(jnp.where(done, y, outs[idx]))
             return (nxt, outs), None
 
-        init = (jnp.zeros_like(xs[0]), jnp.zeros_like(xs))
+        # The carry varies over the stage axis (each stage holds its own
+        # activation and outputs), so its initial value must be marked so.
+        init = jax.lax.pcast(
+            (jnp.zeros_like(xs[0]), jnp.zeros_like(xs)), axis, to="varying"
+        )
         (_, outs), _ = jax.lax.scan(step, init, jnp.arange(n_steps))
         # Only the last stage wrote results; the psum replicates them so the
         # output is unsharded on the stage axis.
         return jax.lax.psum(outs, axis)
 
-    return _shard_map(
+    return shard_map(
         stage_prog,
         mesh=mesh,
         in_specs=(p_specs, P()),

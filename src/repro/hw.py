@@ -2,9 +2,9 @@
 
 Two machines appear in this codebase:
 
-* ``TPU_V5E`` — the *target* hardware for the adapted implementation (this
-  container is CPU-only; kernels are authored for TPU and validated in
-  interpret mode).  Constants are the ones mandated by the assignment:
+* ``TPU_V5E`` — the *target* hardware for the adapted implementation
+  (kernels are authored for TPU and run compiled there; on CPU they run in
+  Pallas interpret mode).  Constants are the ones mandated by the assignment:
   197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s per ICI link.
 * ``UPMEM`` — the paper's evaluation platform (§V-A, §VI-I).  Used by the
   cycle cost model in :mod:`repro.core.pim_cost` that reproduces the paper's

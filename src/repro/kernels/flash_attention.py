@@ -29,12 +29,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.kernels import interpret_mode
 
 Array = jax.Array
 
@@ -116,8 +113,15 @@ def flash_attention(
     softcap: float | None = None,
     blk_q: int = DEFAULT_BLOCK_Q,
     blk_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
+    """Attention over ``q [B, S, H, hd]`` and ``k``/``v [B, T, Hkv, hd]``.
+
+    ``interpret=None`` takes the platform's choice
+    (:func:`repro.kernels.interpret_mode`).
+    """
+    if interpret is None:
+        interpret = interpret_mode()
     b, s, h, hd = q.shape
     t, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
@@ -145,13 +149,11 @@ def flash_attention(
     def kv_map(bh, iq, ik):
         return ((bh // h) * hkv + (bh % h) // rep, ik, 0)
 
-    scratch = []
-    if _VMEM is not None:
-        scratch = [
-            _VMEM((blk_q, 1), jnp.float32),
-            _VMEM((blk_q, 1), jnp.float32),
-            _VMEM((blk_q, hd), jnp.float32),
-        ]
+    scratch = [
+        pltpu.VMEM((blk_q, 1), jnp.float32),
+        pltpu.VMEM((blk_q, 1), jnp.float32),
+        pltpu.VMEM((blk_q, hd), jnp.float32),
+    ]
     out = pl.pallas_call(
         functools.partial(
             _flash_body, blk_q=blk_q, blk_k=blk_k, nk=nk, causal=causal,

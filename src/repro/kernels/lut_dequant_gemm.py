@@ -10,15 +10,20 @@ format, exactly the paper's format-flexibility argument.  The MXU supplies the
 Dataflow per grid step (i, j, kk):
 
     HBM ──codes tile [bF, bKc] (uint8)──▶ VMEM      (Pallas double-buffers)
-    VMEM: decode = Σ_c grid[c]·(codes==c)  — a 2^bw-term one-hot contraction,
-          i.e. the *lookup performed as compute* (VPU), no gather
-    MXU : acc[bB, bF] += x[bB, bK] @ w_t[bF, bK]^T
+    per bit plane q < 8/bw (byte bits [q·bw, (q+1)·bw) hold codes k = j·cpb + q):
+      VMEM: decode = Σ_c grid[c]·(plane==c)  — a 2^bw-term one-hot
+            contraction, i.e. the *lookup performed as compute* (VPU), no gather
+      MXU : acc[bB, bF] += x_q[bB, bKc] @ w_q[bF, bKc]^T
     last kk: out = acc * scale[bF]
 
+Each bit plane decodes as its own ``[bF, bKc]`` tile, so the kernel never
+reshapes a vector (Mosaic refuses the ``[bF, bKc, cpb] -> [bF, bK]`` unpack).
+The wrapper reorders x's columns per K block so that plane q's columns are
+the contiguous slice ``x_blk[:, q·bKc:(q+1)·bKc]``.  ``bKc`` is a multiple of
+128 lanes and ``bF``/``bB`` of 8 sublanes, the TPU block rule.
+
 The K (contraction) axis is the innermost grid dimension; the f32 accumulator
-lives in the revisited output block.  Block shapes keep the MXU dims at
-multiples of 128 and the decoded tile entirely in VMEM.
-"""
+lives in the revisited output block."""
 
 from __future__ import annotations
 
@@ -26,16 +31,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 Array = jax.Array
 
 # Default tile sizes (MXU-aligned; VMEM footprint per step ≈
-# bB*bK*4 + bF*bK*(1+4) + bB*bF*4 ≈ 1.8 MB at 128/512/256 — far below VMEM).
+# bB*bK*4 + bF*bK/cpb*(1+4) + bB*bF*4 ≈ 1 MB at 128/256/512 — far below VMEM).
+# ``block_k`` is a hint: the kernel rounds ``block_k // cpb`` (the packed
+# columns per step) to a multiple of 128 lanes.
 DEFAULT_BLOCK_B = 128
 DEFAULT_BLOCK_F = 256
 DEFAULT_BLOCK_K = 512
+_LANES = 128
 
 
 def _decode_kernel_body(
@@ -47,29 +56,28 @@ def _decode_kernel_body(
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    codes = codes_ref[...]                         # [bF, bKc] uint8
-    cpb = 8 // bw
+    codes = codes_ref[...].astype(jnp.int32)       # [bF, bKc]
+    bkc = codes.shape[1]
     mask = (1 << bw) - 1
-    # Unpack: [bF, bKc] -> [bF, bKc, cpb] -> [bF, bK]
-    shifts = (jnp.arange(cpb, dtype=jnp.int32) * bw).astype(jnp.int32)
-    unpacked = (codes[..., None].astype(jnp.int32) >> shifts) & mask
-    unpacked = unpacked.reshape(codes.shape[0], codes.shape[1] * cpb)
-    # Value-LUT decode as a one-hot contraction (lookup-as-compute).
-    w_t = jnp.zeros(unpacked.shape, dtype=jnp.float32)
-    for c, v in enumerate(grid_values):
-        w_t += jnp.float32(v) * (unpacked == c).astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)             # [bB, bK]
-    acc = jax.lax.dot_general(
-        x,
-        w_t,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                               # [bB, bF]
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for q in range(8 // bw):
+        plane = (codes >> (q * bw)) & mask          # codes k = j*cpb + q
+        # Value-LUT decode as a one-hot contraction (lookup-as-compute).
+        w_t = jnp.zeros(plane.shape, dtype=jnp.float32)
+        for c, v in enumerate(grid_values):
+            w_t += jnp.float32(v) * (plane == c).astype(jnp.float32)
+        x = x_ref[:, q * bkc:(q + 1) * bkc].astype(jnp.float32)   # [bB, bKc]
+        acc += jax.lax.dot_general(
+            x,
+            w_t,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                           # [bB, bF]
     out_ref[...] += acc
 
     @pl.when(kk == nk - 1)
     def _scale():
-        out_ref[...] = out_ref[...] * scale_ref[...][None, :]
+        out_ref[...] = out_ref[...] * scale_ref[...]
 
 
 @functools.partial(
@@ -87,21 +95,26 @@ def lut_dequant_gemm(
     block_b: int = DEFAULT_BLOCK_B,
     block_f: int = DEFAULT_BLOCK_F,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """``y[B,F] = x[B,K] @ (grid[codes] * scale)[F,K]^T``.
 
     ``codes`` is the bit-packed ``[F, ceil(K/cpb)]`` uint8 weight storage of a
     :class:`repro.core.api.QuantizedLinear`.  Padding to block multiples is
-    handled here; the caller passes logical sizes.
+    handled here; the caller passes logical sizes.  ``interpret=None`` takes
+    the platform's choice (:func:`repro.kernels.interpret_mode`).
     """
+    if interpret is None:
+        interpret = interpret_mode()
     b, k_in = x.shape
     f = codes.shape[0]
     cpb = 8 // bw
     assert k_in == k
 
-    block_k = min(block_k, max(cpb, 1 << (k - 1).bit_length()))
-    block_k = max(block_k - block_k % cpb, cpb)
+    kc = -(-k // cpb)                                # packed columns
+    block_kc = max(_LANES, block_k // cpb // _LANES * _LANES)
+    block_kc = min(block_kc, -(-kc // _LANES) * _LANES)
+    block_k = block_kc * cpb
     block_b = min(block_b, max(8, 1 << (b - 1).bit_length()))
     block_f = min(block_f, max(8, 1 << (f - 1).bit_length()))
 
@@ -113,6 +126,8 @@ def lut_dequant_gemm(
         scale = jnp.pad(scale, (0, pf))
     bb, ff, kk = b + pb, f + pf, k + pk
     nk = kk // block_k
+    # Within each K block, put bit plane q's columns (k = j*cpb + q) together.
+    x = x.reshape(bb, nk, block_kc, cpb).transpose(0, 1, 3, 2).reshape(bb, kk)
 
     out = pl.pallas_call(
         functools.partial(
@@ -121,11 +136,11 @@ def lut_dequant_gemm(
         grid=(bb // block_b, ff // block_f, nk),
         in_specs=[
             pl.BlockSpec((block_b, block_k), lambda i, j, kk_: (i, kk_)),
-            pl.BlockSpec((block_f, block_k // cpb), lambda i, j, kk_: (j, kk_)),
-            pl.BlockSpec((block_f,), lambda i, j, kk_: (j,)),
+            pl.BlockSpec((block_f, block_kc), lambda i, j, kk_: (j, kk_)),
+            pl.BlockSpec((1, block_f), lambda i, j, kk_: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_b, block_f), lambda i, j, kk_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bb, ff), jnp.float32),
         interpret=interpret,
-    )(x, codes, scale)
+    )(x, codes, scale.reshape(1, ff))
     return out[:b, :f]

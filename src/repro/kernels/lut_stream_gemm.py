@@ -28,6 +28,11 @@ accumulated in **int32** (bit-exact for integer LUT packs):
 v1 streamed one column pair per step and burned an ``[R, R]`` matmul plus an
 f32 accumulator per lookup; v2 amortizes the weight one-hot over NT columns
 and does no permutation matmul at all.
+
+The kernel runs in interpret mode only.  Its ``(M, 1)`` weight-column and
+``(R, 1)`` LUT-slice blocks are one lane wide, which breaks the TPU rule that
+a block's last two dims be multiples of (8, 128); on TPU it raises
+``NotImplementedError``.  No serving path uses it.
 """
 
 from __future__ import annotations
@@ -38,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 Array = jax.Array
 
@@ -102,14 +109,23 @@ def lut_stream_gemm(
     *,
     r: int,
     nt: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Tiled slice-streaming canonical-LUT GEMM; returns int32 [M, N].
 
     Semantics match :func:`repro.kernels.ref.lut_stream_gemm_ref` exactly
     (int32 partial-product accumulation).  ``nt`` is the N-tile width: slices
-    streamed (and output columns produced) per grid step.
+    streamed (and output columns produced) per grid step.  ``interpret=None``
+    takes the platform's choice (:func:`repro.kernels.interpret_mode`).
     """
+    if interpret is None:
+        interpret = interpret_mode()
+    if not interpret:
+        raise NotImplementedError(
+            "lut_stream_gemm does not compile for TPU: its (M, 1) weight-column "
+            "and (R, 1) LUT-slice blocks break the (8, 128) block rule; it runs "
+            "in interpret mode on CPU only"
+        )
     m, gdim = wpacked.shape
     n = msrank.shape[1]
     nt = max(1, min(nt, n))

@@ -1,9 +1,10 @@
 """Jitted wrappers around the Pallas kernels.
 
 These are the entry points the rest of the framework uses; each dispatches to
-the Pallas kernel (``interpret=True`` on CPU — the kernels are authored for
-TPU) and owns the host-side preparation the paper assigns to the host CPU
-(activation quantization, canonicalization, LUT construction).
+the Pallas kernel (compiled on TPU, interpreted on CPU — the choice is
+:func:`repro.kernels.interpret_mode`'s) and owns the host-side preparation the
+paper assigns to the host CPU (activation quantization, canonicalization, LUT
+construction).
 """
 
 from __future__ import annotations
@@ -30,7 +31,6 @@ def lut_dequant_gemm(
     bw: int,
     k: int,
     grid_kind: str = "int",
-    interpret: bool = True,
     **block_kw,
 ) -> Array:
     """Packed-code GEMM (TPU-optimized path).  x [B,K] -> y [B,F]."""
@@ -42,7 +42,6 @@ def lut_dequant_gemm(
         bw=bw,
         k=k,
         grid_values=tuple(float(v) for v in np.asarray(grid)),
-        interpret=interpret,
         **block_kw,
     )
 
@@ -53,7 +52,6 @@ def lut_stream_gemm_full(
     pack: luts.LutPack,
     *,
     nt: int = 8,
-    interpret: bool = True,
 ) -> Array:
     """Paper-faithful slice-streaming GEMM from raw codes (Pallas kernel v2).
 
@@ -83,6 +81,5 @@ def lut_stream_gemm_full(
         jnp.asarray(pack.reordering.astype(np.int32)),
         r=pack.n_rows,
         nt=nt,
-        interpret=interpret,
     )
     return (out - corr).astype(jnp.float32)

@@ -1,11 +1,9 @@
 """Training driver: fault-tolerant supervised loop with checkpoint/restart.
 
-Runs at whatever scale the process sees: 1 CPU device here; on a real fleet
-the same driver runs under ``jax.distributed`` with the production mesh
-(``--mesh``), FSDP+TP shardings, async checkpoints, and the restart
-supervisor.  Deployment knobs for 1000+ nodes are set in the environment
-block below (collective timeouts for straggler mitigation, async collectives
-for compute/comm overlap).
+Runs at whatever scale the process sees: one CPU device in a smoke run; on a
+real fleet the same driver runs under ``jax.distributed`` with the production
+mesh (``--mesh``), FSDP+TP shardings, async checkpoints, and the restart
+supervisor.
 
 Example (CPU smoke):
     PYTHONPATH=src python -m repro.launch.train --arch chatglm3-6b --smoke \
@@ -17,18 +15,6 @@ from __future__ import annotations
 import argparse
 import time
 
-# Deployment knobs (documented defaults; harmless on CPU):
-#  - NCCL-style collective timeout -> bound straggler blast radius
-#  - async collectives + latency-hiding scheduler -> compute/comm overlap
-import os
-
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
-    "--xla_enable_async_all_gather=true",
-)
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,6 +23,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.dist import sharding as shd
 from repro.ft import supervisor as sup
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.model import build_model
 from repro.train import optimizer as opt
@@ -44,6 +31,7 @@ from repro.train import train_step as ts
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="chatglm3-6b", choices=list(ARCH_IDS))
     ap.add_argument("--smoke", action="store_true", help="reduced config")

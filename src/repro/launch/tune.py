@@ -19,11 +19,13 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core import LutLinearSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.tune import plan_model
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-12b", choices=list(ARCH_IDS))
     ap.add_argument("--smoke", action="store_true", default=True)

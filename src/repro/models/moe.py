@@ -26,10 +26,7 @@ from repro.models import layers
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, linear
 
-try:  # jax>=0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 
 if TYPE_CHECKING:  # import only for annotations: models must not require dist
     from repro.dist.sharding import ShardCtx
@@ -173,7 +170,7 @@ def moe_apply(
             return jax.lax.psum(y_l, tp)
 
         tok_spec = P(dp, None)
-        y = _shard_map(
+        y = shard_map(
             shard_fn,
             mesh=ctx.mesh,
             in_specs=(tok_spec, tok_spec, tok_spec, P(tp, None, None),

@@ -85,7 +85,7 @@ def test_compressed_psum_worst_case_bound():
 # ---------------------------------------------------------------------------
 
 
-MESH8 = AbstractMesh((("data", 4), ("model", 2)))
+MESH8 = AbstractMesh((4, 2), ("data", "model"))
 
 
 def _ctx(**kw):

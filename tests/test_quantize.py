@@ -111,7 +111,7 @@ def test_pallas_mode_matches_dequant():
         codes=q.codes, scale=q.scale, bias=None,
         spec=api.LutLinearSpec(bw=2, ba=4, mode="pallas"), k=q.k,
     )
-    y_pl = api.apply_linear(q_pl, x, interpret=True)
+    y_pl = api.apply_linear(q_pl, x)
     np.testing.assert_allclose(np.asarray(y_pl), np.asarray(y_deq), rtol=2e-4, atol=2e-4)
 
 
