@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.models import layers
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, linear
+from repro.obs import scopes
 
 Array = jax.Array
 
@@ -43,6 +44,7 @@ def _split_heads(x: Array, n: int) -> Array:
     return x.reshape(b, s, n, -1)
 
 
+@scopes.scoped(scopes.KV_WRITE)
 def _cache_write(cache_arr: Array, new: Array, pos) -> Array:
     """Write ``new [B, S, ...]`` into ``cache_arr`` at sequence offset ``pos``.
 
@@ -201,6 +203,7 @@ def _ring_update(cache_arr: Array, new: Array, global_start, tail: int):
     return cache_arr.at[:, idx].set(new[:, -tail:].astype(cache_arr.dtype))
 
 
+@scopes.scoped(scopes.ATTENTION)
 def gqa_attention(
     p: dict,
     x: Array,
@@ -400,6 +403,7 @@ def mla_init(cfg: ModelConfig, key) -> dict:
     }
 
 
+@scopes.scoped(scopes.ATTENTION)
 def mla_attention(
     p: dict,
     x: Array,

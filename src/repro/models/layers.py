@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.core import PreparedLinear, QuantizedLinear, apply_linear
 from repro.core.calibrate import CalibrationProbe, probe_apply
+from repro.obs import scopes
 
 Array = jax.Array
 
@@ -31,7 +32,8 @@ def dense_init(key, k: int, f: int, *, bias: bool = False, scale: float | None =
 
 def linear(p, x: Array) -> Array:
     if isinstance(p, (QuantizedLinear, PreparedLinear)):
-        return apply_linear(p, x)
+        with jax.named_scope(scopes.QLINEAR):
+            return apply_linear(p, x)
     if isinstance(p, CalibrationProbe):   # one-shot scale-capture forward
         return probe_apply(p, x)
     y = x @ p["w"].astype(x.dtype)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.models import attention, ffn, layers, moe, rwkv, ssm
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, linear, norm
+from repro.obs import scopes
 
 Array = jax.Array
 
@@ -333,9 +334,10 @@ def run_segments(
         body_fn = jax.checkpoint(body) if rs.remat else body
         from repro import flags
 
-        (x, aux), nc_stack = jax.lax.scan(
-            body_fn, (x, aux), xs, unroll=flags.scan_unroll()
-        )
+        with jax.named_scope(scopes.LAYERS):
+            (x, aux), nc_stack = jax.lax.scan(
+                body_fn, (x, aux), xs, unroll=flags.scan_unroll()
+            )
         if caches is not None:
             new_caches.append(nc_stack)
     return x, new_caches, aux
@@ -480,6 +482,7 @@ def forward(
     return logits, new_caches, aux
 
 
+@scopes.scoped(scopes.LM_HEAD)
 def lm_head(params: dict, cfg: ModelConfig, x: Array) -> Array:
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(x.dtype))

@@ -7,14 +7,18 @@ records request-lifecycle and per-wave spans **only at its existing host
 syncs** (the O(1)-syncs-per-wave contract is untouched — tokens,
 ``host_syncs`` and ``admissions`` are bit-identical with tracing on or
 off); :mod:`repro.obs.export` renders the stream as Chrome/Perfetto
-``trace_event`` JSON, JSONL, or a human-readable snapshot.
+``trace_event`` JSON, the metrics as JSONL, or a human-readable snapshot.
+
+:mod:`repro.obs.scopes` names what the profiler sees instead: the
+``jax.named_scope`` of each layer inside the compiled programs
+(:data:`SCOPES`) and the continuous driver's host spans (:data:`SPANS`),
+which share the device trace's clock.
 """
 
 from repro.obs.export import (
     metrics_records,
     perfetto_trace,
     snapshot_text,
-    write_jsonl,
     write_metrics_jsonl,
     write_perfetto,
 )
@@ -27,6 +31,7 @@ from repro.obs.metrics import (
     scrape_engine,
     slo_stats,
 )
+from repro.obs.scopes import SCOPES, SPANS, span
 from repro.obs.trace import Event, Observer, Tracer
 
 __all__ = [
@@ -36,6 +41,8 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "Observer",
+    "SCOPES",
+    "SPANS",
     "metrics_records",
     "Tracer",
     "percentile",
@@ -43,7 +50,7 @@ __all__ = [
     "scrape_engine",
     "slo_stats",
     "snapshot_text",
-    "write_jsonl",
+    "span",
     "write_metrics_jsonl",
     "write_perfetto",
 ]

@@ -1,6 +1,6 @@
-"""Exporters: Perfetto ``trace_event`` JSON, JSONL events, text snapshots.
+"""Exporters: Perfetto ``trace_event`` JSON, metrics JSONL, text snapshots.
 
-Three output formats for one event stream:
+Three output formats:
 
 * :func:`perfetto_trace` / :func:`write_perfetto` — the Chrome/Perfetto
   ``trace_event`` format (the JSON object form, ``{"traceEvents": [...]}``)
@@ -11,8 +11,8 @@ Three output formats for one event stream:
   — named via ``thread_name`` metadata events.  Timestamps convert from
   the :func:`repro.timing.clock` seconds domain to the microseconds the
   format requires.
-* :func:`write_jsonl` — one JSON object per line, the machine-diffable form
-  CI archives next to ``BENCH_serve.json``.
+* :func:`write_metrics_jsonl` — the metrics registry, SLO stats and
+  per-request records, one JSON object per line.
 * :func:`snapshot_text` — the human-readable periodic snapshot an operator
   tails: counters, gauges, histogram summaries, and the derived SLO block
   when one is supplied.
@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Iterable, Optional
+from typing import Optional
 
 from repro.obs.trace import Event, Observer, Tracer
 
@@ -103,17 +103,6 @@ def write_perfetto(source, path: str, *,
     """Atomically write the Perfetto trace JSON; returns ``path``."""
     trace = perfetto_trace(source, process_name=process_name)
     _atomic_write_text(path, json.dumps(trace) + "\n")
-    return str(path)
-
-
-def write_jsonl(source, path: str) -> str:
-    """Atomically write one JSON object per event; returns ``path``."""
-    events = _as_events(source)
-    lines = "".join(
-        json.dumps(ev.to_dict(), separators=(",", ":")) + "\n"
-        for ev in events
-    )
-    _atomic_write_text(path, lines)
     return str(path)
 
 

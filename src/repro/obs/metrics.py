@@ -16,10 +16,8 @@ Two derived layers sit on top of the raw registry:
   no matter how busy it was).
 * :func:`scrape_engine` — engine-level gauges read from structures the
   engine already maintains: slot count, cumulative host syncs / swaps /
-  admissions, the prefill bucket usage histogram, the active
-  :class:`repro.tune.ModelPlan`'s per-layer mode mix and packing degrees,
-  and (for stream-mode layers) the planner's buffer-hit ratio via
-  ``stream_stats_for(plan_only=True)`` — counter arithmetic, no GEMM.
+  admissions, the prefill bucket usage histogram, and the active
+  :class:`repro.tune.ModelPlan`'s per-layer mode mix and packing degrees.
 """
 
 from __future__ import annotations
@@ -180,15 +178,12 @@ def slo_stats(records: list[dict]) -> dict:
     }
 
 
-def scrape_engine(engine, *, metrics: Optional[MetricsRegistry] = None,
-                  stream_sample_n: int = 1) -> dict:
+def scrape_engine(engine, *, metrics: Optional[MetricsRegistry] = None) -> dict:
     """Engine-level gauges from existing structures (host-side reads only).
 
     Returns the gauge dict and, when ``metrics`` is given, mirrors the
     scalar values into it.  Plan gauges come from the engine's active
-    :class:`repro.tune.ModelPlan`; stream-layer buffer-hit ratios come from
-    the stream *planner* on a tiny synthetic activation sample
-    (``plan_only=True`` — no GEMM executes)."""
+    :class:`repro.tune.ModelPlan`."""
     out: dict = {
         "batch_slots": engine.batch,
         "max_seq": engine.max_seq,
@@ -212,9 +207,6 @@ def scrape_engine(engine, *, metrics: Optional[MetricsRegistry] = None,
             "modes": modes,
             "p": ps,
         }
-    stream_layers = _stream_buffer_ratios(engine, stream_sample_n)
-    if stream_layers:
-        out["stream_buffer_hit_ratio"] = stream_layers
     if metrics is not None:
         metrics.gauge("batch_slots").set(engine.batch)
         metrics.gauge("host_syncs").set(engine.host_syncs)
@@ -222,38 +214,4 @@ def scrape_engine(engine, *, metrics: Optional[MetricsRegistry] = None,
         if plan is not None:
             metrics.gauge("plan_layers").set(len(plan.layers))
             metrics.gauge("plan_total_bytes").set(plan.total_bytes)
-        for path, ratio in (stream_layers or {}).items():
-            metrics.gauge(f"stream_buffer_hit_ratio:{path}").set(ratio)
     return out
-
-
-def _stream_buffer_ratios(engine, n: int) -> dict:
-    """Planner-derived buffer-hit ratio per stream-mode quantized leaf of
-    the engine's serving tree (empty when none — serving plans exclude the
-    host-simulated stream dataflow, so this usually fires only on
-    explicitly stream-configured trees)."""
-    try:
-        from repro.core import api
-        from repro.tune.plan import map_quantized_leaves
-    except Exception:   # pragma: no cover — core always importable in-tree
-        return {}
-    found: dict[str, float] = {}
-
-    def visit(path, q):
-        spec = getattr(q, "spec", None)
-        if spec is None or getattr(spec, "mode", None) != "stream":
-            return None
-        import numpy as np
-
-        rng = np.random.default_rng(0)
-        x = rng.normal(size=(n, q.k)).astype(np.float32)
-        st = api.stream_stats_for(q, api.jnp.asarray(x), plan_only=True)
-        addressed = st.buffer_hits + st.slices_streamed
-        found[path] = st.buffer_hits / addressed if addressed else 0.0
-        return None
-
-    try:
-        map_quantized_leaves(engine.params, visit)
-    except Exception:
-        return found
-    return found

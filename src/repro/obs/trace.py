@@ -28,7 +28,10 @@ Event vocabulary (``cat`` groups them for the Perfetto exporter's tracks):
 * ``request`` — per-request lifecycle: ``submit`` → ``admit`` (slot, queue
   wait) → ``prefill`` (bucket) → per-wave ``decode`` spans → ``finish`` /
   ``shed`` / ``quarantine``.
-* ``wave`` — per-admission-wave: the wave span, the host-sync duration.
+* ``wave`` — per-admission-wave: the ``serve.wave`` span, its
+  ``serve.prefill`` and its host sync, ``serve.fetch`` — the names the
+  continuous driver's profiler spans carry (:mod:`repro.obs.scopes`), so
+  this export and a device trace speak one vocabulary.
 * ``ops`` — live operations: swap ``stage``/``flip``/``refuse``, supervisor
   ``restart``/``backoff``/``giveup``, ``replay``, ``ckpt_restore``, chaos
   kill points.
@@ -44,6 +47,7 @@ import threading
 from typing import Optional
 
 from repro import timing
+from repro.obs import scopes
 
 
 @dataclasses.dataclass
@@ -62,15 +66,6 @@ class Event:
     dur: float = 0.0
     track: str = "engine"
     args: dict = dataclasses.field(default_factory=dict)
-
-    def to_dict(self) -> dict:
-        d = {"name": self.name, "cat": self.cat, "ph": self.ph,
-             "ts": self.ts, "track": self.track}
-        if self.ph == "X":
-            d["dur"] = self.dur
-        if self.args:
-            d["args"] = self.args
-        return d
 
 
 class Tracer:
@@ -153,22 +148,26 @@ class Observer:
         # consecutive generate() calls on one engine never collide.
         self.requests: dict = {}
         self._gen = 0
+        self._batch: dict = {}          # generation -> batch slots
         self._lock = threading.Lock()   # generation bump only (cold path)
 
     # --- request lifecycle (called by ServeEngine at host syncs) ----------
 
-    def serve_begin(self, n_requests: int, *, decode: str, batch: int) -> int:
-        """A generate() call is starting: all ``n_requests`` are submitted
-        now.  Returns the generation id the engine hands back to the other
-        hooks."""
+    def serve_begin(self, prompt_lens: list[int], *, decode: str,
+                    batch: int) -> int:
+        """A generate() call is starting: all its requests, whose prompt
+        lengths are ``prompt_lens``, are submitted now.  Returns the
+        generation id the engine hands back to the other hooks."""
         with self._lock:
             self._gen += 1
             gen = self._gen
+        self._batch[gen] = batch
         now = timing.clock()
-        for i in range(n_requests):
+        n_requests = len(prompt_lens)
+        for i, plen in enumerate(prompt_lens):
             self.requests[(gen, i)] = {
                 "submit": now, "admit": None, "first": None, "done": None,
-                "tokens": 0, "slot": None,
+                "tokens": 0, "slot": None, "prompt_len": plen,
             }
         self.tracer.instant("submit", cat="request", track="engine",
                             ts=now, n_requests=n_requests, decode=decode)
@@ -183,11 +182,11 @@ class Observer:
         metric registry — all from host-resident values."""
         tr = self.tracer
         m = self.metrics
-        tr.complete(f"wave {rec.wave}", rec.t_start, rec.t_sync, cat="wave",
-                    track="engine", steps=rec.steps,
+        tr.complete(scopes.WAVE, rec.t_start, rec.t_sync, cat="wave",
+                    track="engine", wave=rec.wave, steps=rec.steps,
                     admitted=len(rec.admitted), active=rec.active_slots,
                     queue_depth=rec.queue_depth)
-        tr.complete("host_sync", rec.t_fetch, rec.t_sync, cat="wave",
+        tr.complete(scopes.FETCH, rec.t_fetch, rec.t_sync, cat="wave",
                     track="engine", wave=rec.wave)
         for idx, slot in rec.admitted:
             r = self.requests.get((gen, idx))
@@ -200,8 +199,8 @@ class Observer:
                        bucket=rec.prefill_bucket)
         if rec.admitted and rec.prefill_bucket is not None:
             m.histogram("prefill_bucket").observe(rec.prefill_bucket)
-            tr.complete("prefill", rec.t_start, rec.t_decode, cat="wave",
-                        track="engine", bucket=rec.prefill_bucket,
+            tr.complete(scopes.PREFILL, rec.t_start, rec.t_decode, cat="wave",
+                        track="engine", wave=rec.wave, bucket=rec.prefill_bucket,
                         admitted=len(rec.admitted))
         done = 0
         for idx, slot, toks in rec.emitted:
@@ -233,6 +232,13 @@ class Observer:
         m.counter("tokens_emitted").inc(
             sum(len(t) for _i, _s, t in rec.emitted))
         m.counter("admissions").inc(len(rec.admitted))
+        if rec.admitted and rec.prefill_bucket is not None:
+            # Every admission prefills all the batch's rows to the bucket.
+            m.counter("prefill_positions").inc(
+                self._batch.get(gen, 0) * rec.prefill_bucket)
+            m.counter("prompt_tokens").inc(sum(
+                self.requests.get((gen, idx), {}).get("prompt_len", 0)
+                for idx, _slot in rec.admitted))
         m.counter("requests_finished").inc(done)
         m.histogram("wave_steps").observe(rec.steps)
         m.histogram("host_sync_s").observe(rec.t_sync - rec.t_fetch)
@@ -284,8 +290,7 @@ class Observer:
         """Scrape engine-level gauges from existing structures — slot count,
         sync/swap counters, the active :class:`repro.tune.ModelPlan`'s
         per-layer mode/p mix — into the registry (and return them).  Pure
-        host-side reads; the optional stream buffer-hit ratios come from the
-        *planner* (``stream_stats_for(plan_only=True)``), never a GEMM."""
+        host-side reads."""
         from repro.obs.metrics import scrape_engine
 
         return scrape_engine(engine, metrics=self.metrics)

@@ -83,6 +83,12 @@ pads through their state; only attention archs get exact invariance.)
   tracing adds zero device transfers: tokens, ``host_syncs`` and
   ``admissions`` are bit-identical with ``obs`` on or off
   (``tests/test_obs.py``).
+* *Profiler spans*: each continuous-driver wave runs inside a
+  ``serve.wave`` step span whose phases (``serve.admit``, ``serve.prefill``,
+  ``serve.decode``, ``serve.fetch``, ``serve.emit``) are host spans on the
+  ``jax.profiler`` timeline, and every layer of the jitted programs carries
+  a ``jax.named_scope`` (:mod:`repro.obs.scopes`).  Both are inert when no
+  profiler runs, and change no token, sync or admission when one does.
 """
 
 from __future__ import annotations
@@ -100,6 +106,7 @@ import numpy as np
 
 from repro import timing
 from repro.models.model import Model
+from repro.obs import scopes
 
 Array = jax.Array
 
@@ -153,10 +160,11 @@ def make_decode_scan(model: Model, *, ctx=None):
             nxt = jnp.argmax(lg[:, -1:, :], axis=-1).astype(jnp.int32)
             return (nxt, caches, pos + 1), nxt[:, 0]
 
-        (_, caches, _), ys = jax.lax.scan(
-            body, (tok0, caches, jnp.asarray(pos0, jnp.int32)), None,
-            length=length - 1,
-        )
+        with jax.named_scope(scopes.DECODE_LOOP):   # see make_decode_wave
+            (_, caches, _), ys = jax.lax.scan(
+                body, (tok0, caches, jnp.asarray(pos0, jnp.int32)), None,
+                length=length - 1,
+            )
         toks = jnp.concatenate([tok0, ys.T], axis=1)                 # [B, L]
         step_ix = jnp.arange(length, dtype=jnp.int32)[None, :]
         return jnp.where(step_ix < max_new[:, None], toks, -1), caches
@@ -195,9 +203,13 @@ def make_decode_wave(model: Model, *, ctx=None, out_cap: int):
             out = out.at[:, t + 1].set(jnp.where(active, nxt[:, 0], -1))
             return (t + 1, nxt, caches, pos + act, out)
 
-        _, token, caches, pos, out = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), token, caches, pos, out0)
-        )
+        # The loop carries the stacked caches from step to step; the copies
+        # XLA inserts to carry them have no op_name of their own and take
+        # this scope from the loop (``repro.obs.scopes``).
+        with jax.named_scope(scopes.DECODE_LOOP):
+            _, token, caches, pos, out = jax.lax.while_loop(
+                cond, body, (jnp.int32(0), token, caches, pos, out0)
+            )
         return token, caches, pos, out
 
     return decode_wave
@@ -387,7 +399,8 @@ class ServeEngine:
         self._serving = True
         if self.obs is not None:
             self._obs_gen = self.obs.serve_begin(
-                len(requests), decode=self.decode, batch=self.batch
+                [len(r.prompt) for r in requests], decode=self.decode,
+                batch=self.batch,
             )
         try:
             if self.decode == "scan":
@@ -567,98 +580,112 @@ class ServeEngine:
             # staged hot-swap installs atomically here — new admissions
             # prefill under the new tree, carried slots continue under it.
             self._poll_swap(wave)
-            t_wave = timing.clock()     # host-side read at the boundary
-            plen_b: Optional[int] = None
-            # Admission: FIFO into free slots, as many as legally share one
-            # prefill extent (singletons always fit, so the queue drains).
-            admitted: list[int] = []
-            wave_reqs: list[Request] = []
-            for s in range(b):
-                if slot_req[s] is not None or qi >= len(queue):
-                    continue
-                cand = requests[queue[qi]]
-                if not self._wave_fits(wave_reqs + [cand]):
-                    break
-                wave_reqs.append(cand)
-                slot_req[s] = queue[qi]
-                slot_rem[s] = cand.max_new_tokens - 1
-                admitted.append(s)
-                qi += 1
-            if admitted:
-                plen_b = self._wave_bucket(wave_reqs)
-                self.bucket_counts[plen_b] = self.bucket_counts.get(plen_b, 0) + 1
-                toks = np.zeros((b, plen_b), np.int32)
-                npad = np.zeros((b,), np.int32)
-                amask = np.zeros((b,), bool)
-                for s in admitted:
-                    pr = requests[slot_req[s]].prompt
-                    toks[s, plen_b - len(pr) :] = pr
-                    npad[s] = plen_b - len(pr)
-                    amask[s] = True
-                # Prefill must see a ZERO cache, not a reused scratch:
-                # recurrent units (M/R/S) consume the incoming state as their
-                # initial state during prefill, so a previous occupant's
-                # state would leak into the new request.  (Attention rows
-                # would be safe — stale keys past the written extent are
-                # never attended.)
-                fresh = self.model.init_cache(b, self.max_seq, dtype=jnp.float32)
-                lg, fresh = self._prefill(
-                    self.params, jnp.asarray(toks), fresh,
-                    pad_len=jnp.asarray(npad),
+            with jax.profiler.StepTraceAnnotation(scopes.WAVE, step_num=wave):
+                t_wave = timing.clock()     # host-side read at the boundary
+                plen_b: Optional[int] = None
+                admitted: list[int] = []
+                wave_reqs: list[Request] = []
+                with scopes.span(scopes.ADMIT):
+                    # Admission: FIFO into free slots, as many as legally
+                    # share one prefill extent (singletons always fit, so
+                    # the queue drains).
+                    for s in range(b):
+                        if slot_req[s] is not None or qi >= len(queue):
+                            continue
+                        cand = requests[queue[qi]]
+                        if not self._wave_fits(wave_reqs + [cand]):
+                            break
+                        wave_reqs.append(cand)
+                        slot_req[s] = queue[qi]
+                        slot_rem[s] = cand.max_new_tokens - 1
+                        admitted.append(s)
+                        qi += 1
+                    if admitted:
+                        plen_b = self._wave_bucket(wave_reqs)
+                        self.bucket_counts[plen_b] = (
+                            self.bucket_counts.get(plen_b, 0) + 1)
+                        toks = np.zeros((b, plen_b), np.int32)
+                        npad = np.zeros((b,), np.int32)
+                        amask = np.zeros((b,), bool)
+                        for s in admitted:
+                            pr = requests[slot_req[s]].prompt
+                            toks[s, plen_b - len(pr) :] = pr
+                            npad[s] = plen_b - len(pr)
+                            amask[s] = True
+                        toks, npad, amask = (jnp.asarray(toks), jnp.asarray(npad),
+                                             jnp.asarray(amask))
+                if admitted:
+                    with scopes.span(scopes.PREFILL):
+                        # Prefill must see a ZERO cache, not a reused
+                        # scratch: recurrent units (M/R/S) consume the
+                        # incoming state as their initial state during
+                        # prefill, so a previous occupant's state would leak
+                        # into the new request.  (Attention rows would be
+                        # safe — stale keys past the written extent are
+                        # never attended.)
+                        fresh = self.model.init_cache(b, self.max_seq,
+                                                      dtype=jnp.float32)
+                        lg, fresh = self._prefill(self.params, toks, fresh,
+                                                  pad_len=npad)
+                        tok0 = jnp.argmax(lg[:, -1:, :], axis=-1).astype(jnp.int32)
+                        caches, (token, pos, pad) = self._admit_merge(
+                            caches, fresh, (token, pos, pad),
+                            (tok0, jnp.full((b,), plen_b, jnp.int32), npad),
+                            amask,
+                        )
+                    self.admissions.extend((slot_req[s], s) for s in admitted)
+                active = np.array([s is not None for s in slot_req])
+                steps = min(
+                    (slot_rem[s] for s in range(b) if slot_req[s] is not None),
+                    default=0,
                 )
-                tok0 = jnp.argmax(lg[:, -1:, :], axis=-1).astype(jnp.int32)
-                caches, (token, pos, pad) = self._admit_merge(
-                    caches, fresh, (token, pos, pad),
-                    (tok0, jnp.full((b,), plen_b, jnp.int32), jnp.asarray(npad)),
-                    jnp.asarray(amask),
-                )
-                self.admissions.extend((slot_req[s], s) for s in admitted)
-            active = np.array([s is not None for s in slot_req])
-            steps = min(
-                (slot_rem[s] for s in range(b) if slot_req[s] is not None),
-                default=0,
-            )
-            t_decode = timing.clock()   # decode program dispatched (async)
-            token, caches, pos, out_dev = self._decode_wave(
-                self.params, token, caches, pos, pad,
-                jnp.asarray(active), jnp.int32(steps),
-            )
-            # The wave's single device->host sync; steps is host-known, so
-            # only the used columns cross (the slice is outside the trace).
-            t_fetch = timing.clock()
-            mat = self._fetch(out_dev[:, : 1 + steps])
-            t_sync = timing.clock()
-            emitted: list[tuple[int, int, list[int]]] = []
-            for s in range(b):
-                i = slot_req[s]
-                if i is None:
-                    continue
-                lo = 0 if s in admitted else 1   # col 0 = wave-start token
-                emitted.append((i, s, [int(t) for t in mat[s, lo : 1 + steps]]))
-            # Fires after the sync but before outs/slot bookkeeping: the
-            # request log's write point.  A crash here (injected or real)
-            # lands after the wave's tokens are durable, so replay resumes
-            # *including* this wave with no duplicates.  Every record field
-            # is already host-resident — building it syncs nothing.
-            self._dispatch_wave(WaveRecord(
-                wave=wave,
-                admitted=[(slot_req[s], s) for s in admitted],
-                emitted=emitted,
-                finished=frozenset(
-                    i for i, s, _t in emitted if slot_rem[s] == steps
-                ),
-                steps=steps,
-                t_start=t_wave, t_decode=t_decode,
-                t_fetch=t_fetch, t_sync=t_sync,
-                prefill_bucket=plen_b,
-                queue_depth=len(queue) - qi,
-                active_slots=int(active.sum()),
-            ))
-            for i, s, toks_w in emitted:
-                outs[i].extend(toks_w)
-                slot_rem[s] -= steps
-                if slot_rem[s] == 0:
-                    slot_req[s] = None           # freed: next wave re-admits
+                with scopes.span(scopes.DECODE):
+                    t_decode = timing.clock()   # decode program dispatched
+                    token, caches, pos, out_dev = self._decode_wave(
+                        self.params, token, caches, pos, pad,
+                        jnp.asarray(active), jnp.int32(steps),
+                    )
+                # The wave's single device->host sync; steps is host-known,
+                # so only the used columns cross (the slice is outside the
+                # trace).
+                with scopes.span(scopes.FETCH):
+                    t_fetch = timing.clock()
+                    mat = self._fetch(out_dev[:, : 1 + steps])
+                    t_sync = timing.clock()
+                with scopes.span(scopes.EMIT):
+                    emitted: list[tuple[int, int, list[int]]] = []
+                    for s in range(b):
+                        i = slot_req[s]
+                        if i is None:
+                            continue
+                        lo = 0 if s in admitted else 1   # col 0 = wave-start token
+                        emitted.append(
+                            (i, s, [int(t) for t in mat[s, lo : 1 + steps]]))
+                    # Fires after the sync but before outs/slot bookkeeping:
+                    # the request log's write point.  A crash here (injected
+                    # or real) lands after the wave's tokens are durable, so
+                    # replay resumes *including* this wave with no
+                    # duplicates.  Every record field is already
+                    # host-resident — building it syncs nothing.
+                    self._dispatch_wave(WaveRecord(
+                        wave=wave,
+                        admitted=[(slot_req[s], s) for s in admitted],
+                        emitted=emitted,
+                        finished=frozenset(
+                            i for i, s, _t in emitted if slot_rem[s] == steps
+                        ),
+                        steps=steps,
+                        t_start=t_wave, t_decode=t_decode,
+                        t_fetch=t_fetch, t_sync=t_sync,
+                        prefill_bucket=plen_b,
+                        queue_depth=len(queue) - qi,
+                        active_slots=int(active.sum()),
+                    ))
+                    for i, s, toks_w in emitted:
+                        outs[i].extend(toks_w)
+                        slot_rem[s] -= steps
+                        if slot_rem[s] == 0:
+                            slot_req[s] = None       # freed: next wave re-admits
             wave += 1
         return outs
 

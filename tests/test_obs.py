@@ -10,6 +10,7 @@ import dataclasses as dc
 import json
 import math
 import os
+import pathlib
 
 import jax
 import numpy as np
@@ -29,10 +30,10 @@ from repro.obs import (
     scrape_engine,
     slo_stats,
     snapshot_text,
-    write_jsonl,
     write_metrics_jsonl,
     write_perfetto,
 )
+from repro.obs import scopes
 from repro.obs.metrics import Histogram, MetricsRegistry
 from repro.obs.trace import Event
 from repro.serve.ops import LiveServer
@@ -70,10 +71,42 @@ def _reqs(cfg, budgets=(6, 2, 4, 2), seed=0):
 # --- the zero-sync contract ------------------------------------------------
 
 
-@pytest.mark.parametrize("decode", ["scan", "chunked", "loop"])
-def test_tracing_is_invisible_to_tokens_syncs_and_admissions(decode):
+def _host_spans(trace_dir) -> list:
+    """``(start, end, name, step_num)`` of the ``serve.*`` spans on the
+    host's Python thread of the one profiler trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = pathlib.Path(trace_dir).rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in scopes.SPANS:
+                    step = dict(ev.stats).get("step_num")
+                    out.append((ev.start_ns, ev.start_ns + ev.duration_ns,
+                                ev.name, step))
+    return sorted(out)
+
+
+def _phases_by_wave(spans) -> list:
+    """The phase names inside each ``serve.wave`` span, in time order."""
+    return [[n for s, e, n, _ in spans
+             if n != scopes.WAVE and ws <= s and e <= we]
+            for ws, we, wn, _ in spans if wn == scopes.WAVE]
+
+
+@pytest.mark.parametrize("decode,profiled", [
+    pytest.param("scan", False, id="scan"),
+    pytest.param("chunked", False, id="chunked"),
+    pytest.param("loop", False, id="loop"),
+    pytest.param("scan", True, id="scan-profiled"),
+])
+def test_tracing_is_invisible_to_tokens_syncs_and_admissions(
+        decode, profiled, tmp_path):
     """THE obs gate: tokens, host_syncs and admission order bit-identical
-    with tracing on vs off, on every decode driver."""
+    with tracing on vs off, on every decode driver — and with the profiler
+    recording the continuous driver's host spans, each wave's phases in
+    order inside its ``serve.wave`` span."""
     cfg, model, tree = _tiny_model()
     reqs = _reqs(cfg)
     plain = ServeEngine(model, tree, batch=2, max_seq=32, decode=decode)
@@ -82,7 +115,26 @@ def test_tracing_is_invisible_to_tokens_syncs_and_admissions(decode):
     obs = Observer()
     traced = ServeEngine(model, tree, batch=2, max_seq=32, decode=decode,
                          obs=obs)
-    got = traced.generate(reqs)
+    if profiled:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            got = traced.generate(reqs)
+        finally:
+            jax.profiler.stop_trace()
+        spans = _host_spans(tmp_path)
+        waves = [s for s in spans if s[2] == scopes.WAVE]
+        assert [w[3] for w in waves] == list(range(traced.host_syncs))
+        # The Observer's after-the-fact serve.prefill events name the waves
+        # that admitted: exactly those show a serve.prefill span.
+        admitting = {e.args["wave"] for e in obs.tracer.events()
+                     if e.name == scopes.PREFILL}
+        assert 0 in admitting
+        assert _phases_by_wave(spans) == [
+            [scopes.ADMIT] + [scopes.PREFILL] * (w in admitting)
+            + [scopes.DECODE, scopes.FETCH, scopes.EMIT]
+            for w in range(len(waves))]
+    else:
+        got = traced.generate(reqs)
     assert got == want
     assert traced.host_syncs == plain.host_syncs
     assert traced.admissions == plain.admissions
@@ -96,16 +148,18 @@ def test_tracing_is_invisible_to_tokens_syncs_and_admissions(decode):
 
 
 def test_wave_spans_record_existing_sync_timestamps():
-    """Continuous-driver wave spans: one wave span + one host_sync span per
-    admission wave, timestamps ordered t_start <= t_fetch <= t_sync."""
+    """Continuous-driver wave spans: one ``serve.wave`` span + one
+    ``serve.fetch`` (host sync) span per admission wave, under the names of
+    the profiler's host spans, each carrying its wave index."""
     cfg, model, tree = _tiny_model()
     obs = Observer()
     eng = ServeEngine(model, tree, batch=2, max_seq=32, obs=obs)
     eng.generate(_reqs(cfg))
     waves = [e for e in obs.tracer.events()
-             if e.cat == "wave" and e.name.startswith("wave ")]
-    syncs = [e for e in obs.tracer.events() if e.name == "host_sync"]
+             if e.cat == "wave" and e.name == scopes.WAVE]
+    syncs = [e for e in obs.tracer.events() if e.name == scopes.FETCH]
     assert len(waves) == eng.host_syncs == len(syncs)
+    assert [e.args["wave"] for e in waves] == list(range(eng.host_syncs))
     for e in waves:
         assert e.ph == "X" and e.dur >= 0
 
@@ -202,10 +256,10 @@ def test_perfetto_export_loads_and_has_request_lifecycle_spans(tmp_path):
 def test_jsonl_and_metrics_exports(tmp_path):
     cfg, model, tree = _tiny_model()
     obs = Observer()
-    ServeEngine(model, tree, batch=2, max_seq=32, obs=obs).generate(_reqs(cfg))
-    ev_path = write_jsonl(obs, str(tmp_path / "events.jsonl"))
-    lines = [json.loads(ln) for ln in open(ev_path)]
-    assert len(lines) == len(obs.tracer)
+    eng = ServeEngine(model, tree, batch=2, max_seq=32, obs=obs)
+    seen = []
+    eng.on_wave = seen.append
+    eng.generate(_reqs(cfg))
     m_path = write_metrics_jsonl(obs, str(tmp_path / "metrics.jsonl"),
                                  extra={"run": 1})
     recs = [json.loads(ln) for ln in open(m_path)]
@@ -215,6 +269,10 @@ def test_jsonl_and_metrics_exports(tmp_path):
     snap = recs[0]
     assert snap["counters"]["tokens_emitted"] == 14
     assert snap["counters"]["requests_finished"] == 4
+    # Every admitting wave prefills all 2 rows to its bucket.
+    assert snap["counters"]["prompt_tokens"] == 3 + 4 + 5 + 6
+    assert snap["counters"]["prefill_positions"] == sum(
+        2 * r.prefill_bucket for r in seen if r.admitted)
     text = snapshot_text(obs)
     assert "goodput" in text and "ttft" in text
 
