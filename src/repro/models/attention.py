@@ -1,14 +1,16 @@
 """Attention variants: GQA (w/ sliding window + logit softcap), MLA, cross.
 
 All functions are cache-aware: ``cache=None`` runs full-sequence (train /
-prefill-style) attention; otherwise ``cache`` is a dict of preallocated
-buffers written at ``pos`` (decode).  MLA caches the *compressed* latent
-(DeepSeek-style absorbed formulation), which is what makes the 32k decode
-cells of deepseek-v2-lite cheap on HBM.
+prefill-style) attention; otherwise ``cache`` is a dict of :class:`LayerRows`,
+the layer's rows inside its segment's stacked buffers, written in place at
+``pos`` (decode).  MLA caches the *compressed* latent (DeepSeek-style
+absorbed formulation), which is what makes the 32k decode cells of
+deepseek-v2-lite cheap on HBM.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
@@ -44,21 +46,50 @@ def _split_heads(x: Array, n: int) -> Array:
     return x.reshape(b, s, n, -1)
 
 
+@dataclasses.dataclass(frozen=True)
+class LayerRows:
+    """One layer's cache leaf where it lives: ``buf [n_units, B, T, ...]`` is
+    the segment's stacked buffer and ``layer`` the (traced) unit index.
+
+    Writes scatter the new rows straight into ``buf`` and reads slice the
+    layer out where it is consumed, so no whole-layer copy of the cache is
+    made per step (the layer scan carries ``buf``).  ``shape`` and ``dtype``
+    are the layer's own.
+    """
+
+    buf: Array
+    layer: Array
+
+    @property
+    def shape(self) -> tuple:
+        return self.buf.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.buf.dtype
+
+    def read(self) -> Array:
+        return jax.lax.dynamic_index_in_dim(self.buf, self.layer, 0, keepdims=False)
+
+
 @scopes.scoped(scopes.KV_WRITE)
-def _cache_write(cache_arr: Array, new: Array, pos) -> Array:
-    """Write ``new [B, S, ...]`` into ``cache_arr`` at sequence offset ``pos``.
+def _cache_write(rows: LayerRows, new: Array, pos) -> LayerRows:
+    """Write ``new [B, S, ...]`` into the layer's ``rows`` at sequence offset
+    ``pos``.
 
     ``pos`` may be a scalar (all rows share the offset — prefill and chunked
     decode) or a ``[B]`` vector of per-slot offsets (continuous-batching
     decode, where ``S == 1`` and every slot sits at its own depth).
     """
     p = jnp.asarray(pos)
-    new = new.astype(cache_arr.dtype)
+    new = new.astype(rows.dtype)
     if p.ndim:
-        b = cache_arr.shape[0]
-        return cache_arr.at[jnp.arange(b), p].set(new[:, 0])
-    starts = (0, pos) + (0,) * (cache_arr.ndim - 2)
-    return jax.lax.dynamic_update_slice(cache_arr, new, starts)
+        b = new.shape[0]
+        buf = rows.buf.at[rows.layer, jnp.arange(b), p].set(new[:, 0])
+    else:
+        starts = (rows.layer, 0, p) + (0,) * (rows.buf.ndim - 3)
+        buf = jax.lax.dynamic_update_slice(rows.buf, new[None], starts)
+    return dataclasses.replace(rows, buf=buf)
 
 
 def _key_mask(kpos: Array, qpos: Array, pad_len, window) -> Array:
@@ -187,20 +218,18 @@ def _quant_rows(x: Array) -> tuple[Array, Array]:
     return codes, scale
 
 
-def _ring_update(cache_arr: Array, new: Array, global_start, tail: int):
-    """Write the last ``tail`` tokens of ``new`` into the ring buffer at their
-    ``global_position % W`` slots.  ``global_start`` may be a per-slot ``[B]``
-    vector (continuous-batching decode)."""
-    w = cache_arr.shape[1]
-    gs = jnp.asarray(global_start)
-    if gs.ndim:
-        b = cache_arr.shape[0]
-        idx = (gs[:, None] + jnp.arange(tail)[None, :]) % w          # [B, tail]
-        return cache_arr.at[jnp.arange(b)[:, None], idx].set(
-            new[:, -tail:].astype(cache_arr.dtype)
-        )
-    idx = (global_start + jnp.arange(tail)) % w
-    return cache_arr.at[:, idx].set(new[:, -tail:].astype(cache_arr.dtype))
+def _ring_update(rows: LayerRows, new: Array, global_start, tail: int) -> LayerRows:
+    """Write the last ``tail`` tokens of ``new`` into the layer's ring buffer
+    at their ``global_position % W`` slots.  ``global_start`` may be a
+    per-slot ``[B]`` vector (continuous-batching decode)."""
+    w = rows.shape[1]
+    gs = jnp.reshape(jnp.asarray(global_start), (-1, 1))            # [B|1, 1]
+    idx = (gs + jnp.arange(tail)[None, :]) % w                      # [B|1, tail]
+    b = new.shape[0]
+    buf = rows.buf.at[rows.layer, jnp.arange(b)[:, None], idx].set(
+        new[:, -tail:].astype(rows.dtype)
+    )
+    return dataclasses.replace(rows, buf=buf)
 
 
 @scopes.scoped(scopes.ATTENTION)
@@ -210,7 +239,7 @@ def gqa_attention(
     *,
     cfg: ModelConfig,
     positions: Array,                  # [B, S] logical positions (RoPE + mask)
-    cache: Optional[dict] = None,      # {"k": [B, Smax, Hkv, hd], "v": ...}
+    cache: Optional[dict] = None,      # {"k": LayerRows [B, Smax, Hkv, hd], "v": ...}
     pos: Optional[Array] = None,       # cache write offset: scalar or [B]
     window: Optional[int] = None,
     causal: bool = True,
@@ -248,8 +277,9 @@ def gqa_attention(
     if cache is not None and window is not None and cache["k"].shape[1] <= window:
         w = cache["k"].shape[1]
         if s == 1:  # decode: write slot pos % W, attend over the ring
-            kc = _ring_update(cache["k"], k, pos, 1)
-            vc = _ring_update(cache["v"], v, pos, 1)
+            new_cache = {"k": _ring_update(cache["k"], k, pos, 1),
+                         "v": _ring_update(cache["v"], v, pos, 1)}
+            kc, vc = new_cache["k"].read(), new_cache["v"].read()
             slots = jnp.arange(w)
             pos2 = jnp.reshape(jnp.asarray(pos), (-1, 1))  # [1|B, 1]
             kpos_global = pos2 - ((pos2 - slots[None]) % w)  # in (pos-W, pos]
@@ -274,23 +304,23 @@ def gqa_attention(
                 out = _attend(q, k, v, mask=m, softcap_val=cfg.attn_logit_softcap,
                               bf16_operands=cfg.attend_bf16)
             tail = min(s, w)
-            kc = _ring_update(cache["k"], k, pos + s - tail, tail)
-            vc = _ring_update(cache["v"], v, pos + s - tail, tail)
+            new_cache = {"k": _ring_update(cache["k"], k, pos + s - tail, tail),
+                         "v": _ring_update(cache["v"], v, pos + s - tail, tail)}
         y = linear(p["wo"], out.reshape(b, s, cfg.n_heads * hd))
-        return y, {"k": kc, "v": vc}
+        return y, new_cache
 
     # int8 KV cache (§Perf): store codes + per-row scales; attention reads
     # half the bytes.  Reuses the paper's symmetric-quantization machinery.
     if cache is not None and "k_s" in cache:
         k8, ks = _quant_rows(k)
         v8, vs = _quant_rows(v)
-        kc8 = _cache_write(cache["k"], k8, pos)
-        ksc = _cache_write(cache["k_s"], ks, pos)
-        vc8 = _cache_write(cache["v"], v8, pos)
-        vsc = _cache_write(cache["v_s"], vs, pos)
-        kc = kc8.astype(jnp.float32) * ksc[..., None]
-        vc = vc8.astype(jnp.float32) * vsc[..., None]
-        new_cache = {"k": kc8, "k_s": ksc, "v": vc8, "v_s": vsc}
+        new_cache = {"k": _cache_write(cache["k"], k8, pos),
+                     "k_s": _cache_write(cache["k_s"], ks, pos),
+                     "v": _cache_write(cache["v"], v8, pos),
+                     "v_s": _cache_write(cache["v_s"], vs, pos)}
+        r = {name: rows.read() for name, rows in new_cache.items()}
+        kc = r["k"].astype(jnp.float32) * r["k_s"][..., None]
+        vc = r["v"].astype(jnp.float32) * r["v_s"][..., None]
         t = kc.shape[1]
         if s > CHUNK_THRESHOLD and s % CHUNK_SIZE == 0:
             out = _attend_chunked(
@@ -307,9 +337,9 @@ def gqa_attention(
         return y, new_cache
 
     if cache is not None:
-        kc = _cache_write(cache["k"], k, pos)
-        vc = _cache_write(cache["v"], v, pos)
-        new_cache = {"k": kc, "v": vc}
+        new_cache = {"k": _cache_write(cache["k"], k, pos),
+                     "v": _cache_write(cache["v"], v, pos)}
+        kc, vc = new_cache["k"].read(), new_cache["v"].read()
         if s > CHUNK_THRESHOLD and s % CHUNK_SIZE == 0:
             out = _attend_chunked(
                 q, kc, vc, positions, window=window,
@@ -410,7 +440,7 @@ def mla_attention(
     *,
     cfg: ModelConfig,
     positions: Array,
-    cache: Optional[dict] = None,   # {"ckv": [B, Smax, lora], "krope": [B, Smax, rope]}
+    cache: Optional[dict] = None,   # {"ckv": LayerRows [B, Smax, lora], "krope": ...}
     pos: Optional[Array] = None,    # cache write offset: scalar or [B]
     ctx=None,                       # ShardCtx (prefill head-sharding hint)
     pad_len: Optional[Array] = None,  # [B] left-pad lengths: pad keys masked
@@ -434,9 +464,9 @@ def mla_attention(
     q_lat = jnp.einsum("bshd,lhd->bshl", q_nope.astype(jnp.float32), wkup)
 
     if cache is not None:
-        ckv_c = _cache_write(cache["ckv"], ckv, pos)
-        krope_c = _cache_write(cache["krope"], krope, pos)
-        new_cache = {"ckv": ckv_c, "krope": krope_c}
+        new_cache = {"ckv": _cache_write(cache["ckv"], ckv, pos),
+                     "krope": _cache_write(cache["krope"], krope, pos)}
+        ckv_c, krope_c = new_cache["ckv"].read(), new_cache["krope"].read()
     else:
         ckv_c, krope_c = ckv, krope
         new_cache = None

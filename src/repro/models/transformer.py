@@ -12,9 +12,10 @@ single unit).  A unit is described by a pattern string:
 Examples: gemma2 = [("LG", 13)], zamba2 = [("MMMMMS", 13), ("M", 3)],
 deepseek-v2-lite = [("F", 1), ("D", 26)] (F = dense-FFN first layer).
 
-Caches follow the same segmentation: each segment carries stacked per-unit
-cache pytrees, scanned alongside the parameters.  One ``forward`` serves
-train (no cache), prefill (cache + pos=0) and decode (cache + pos=t).
+Caches follow the same segmentation: each segment's stacked per-unit cache
+pytree rides in its layer scan's carry, and every unit reads and writes its
+own layer of it in place.  One ``forward`` serves train (no cache), prefill
+(cache + pos=0) and decode (cache + pos=t).
 """
 
 from __future__ import annotations
@@ -235,11 +236,11 @@ def _apply_sublayer(
         return x, new_attn_cache, aux
     if ch == "M":
         h = norm(p["norm"], x, nk, eps)
-        y, new_state = ssm.ssm_apply(p["ssm"], h, cfg, cache)
+        y, new_state = ssm.ssm_apply(p["ssm"], h, cfg, _read(cache))
         return x + y, new_state, aux
     if ch == "S":
         h = norm(p["norm"], x, nk, eps)
-        y, new_m = ssm.ssm_apply(p["ssm"], h, cfg, cache["mamba"] if cache else None)
+        y, new_m = ssm.ssm_apply(p["ssm"], h, cfg, _read(cache["mamba"]) if cache else None)
         x = x + y
         sp = rs.shared_attn
         h = norm(sp["attn_norm"], x, nk, eps)
@@ -255,7 +256,7 @@ def _apply_sublayer(
         return x, new_cache, aux
     if ch == "R":
         h = norm(p["tm_norm"], x, nk, eps)
-        y, new_state = rwkv.rwkv_time_mix(p["time_mix"], h, cfg, cache)
+        y, new_state = rwkv.rwkv_time_mix(p["time_mix"], h, cfg, _read(cache))
         x = x + y
         h = norm(p["cm_norm"], x, nk, eps)
         y, new_state = rwkv.rwkv_channel_mix(p["channel_mix"], h, cfg, new_state)
@@ -275,7 +276,7 @@ def _apply_sublayer(
                 ck = ck.astype(cache["ck"].dtype)
                 cv = cv.astype(cache["cv"].dtype)
         else:
-            ck, cv = cache["ck"], cache["cv"]
+            ck, cv = cache["ck"].read(), cache["cv"].read()
         x = x + attention.cross_attention(p["cross"], h, cfg=cfg, enc_k=ck, enc_v=cv)
         h = norm(p["ffn_norm"], x, nk, eps)
         x = x + ffn.ffn_apply(p["ffn"], h, cfg)
@@ -292,6 +293,21 @@ def _apply_sublayer(
         h = norm(p["ffn_norm"], x, nk, eps)
         return x + ffn.ffn_apply(p["ffn"], h, cfg), None, aux
     raise ValueError(ch)
+
+
+def _read(cache):
+    """The whole state of a sublayer that replaces its state every step (SSM,
+    RWKV), as arrays; :func:`_write_back` stores what it returns."""
+    return jax.tree.map(attention.LayerRows.read, cache)
+
+
+def _write_back(stack: Array, new, layer: Array) -> Array:
+    """One leaf of a unit's new cache into its segment's stack: rows written
+    in place come back as :class:`attention.LayerRows` holding the updated
+    stack; a state returned whole replaces the unit's slice."""
+    if isinstance(new, attention.LayerRows):
+        return new.buf
+    return jax.lax.dynamic_update_index_in_dim(stack, new.astype(stack.dtype), layer, 0)
 
 
 def unit_apply(rs: RunState, pattern: str, unit_p: dict, x: Array, unit_cache, aux):
@@ -317,29 +333,43 @@ def run_segments(
     new_caches = [] if caches is not None else None
     for si, (pattern, n_units) in enumerate(segments(cfg)):
         p_stack = seg_params[si]
-        c_stack = caches[si] if caches is not None else None
         if rs.ctx is not None:
             x = rs.ctx.constrain_acts(x)
 
-        def body(carry, xs):
-            x_c, aux_c = carry
-            if c_stack is not None:
-                unit_p, unit_c = xs
-            else:
-                unit_p, unit_c = xs, None
-            x_c, nc, aux_c = unit_apply(rs, pattern, unit_p, x_c, unit_c, aux_c)
-            return (x_c, aux_c), nc
-
-        xs = (p_stack, c_stack) if c_stack is not None else p_stack
-        body_fn = jax.checkpoint(body) if rs.remat else body
         from repro import flags
 
+        if caches is None:
+            def body(carry, unit_p):
+                x_c, aux_c = carry
+                x_c, _, aux_c = unit_apply(rs, pattern, unit_p, x_c, None, aux_c)
+                return (x_c, aux_c), None
+
+            body_fn = jax.checkpoint(body) if rs.remat else body
+            with jax.named_scope(scopes.LAYERS):
+                (x, aux), _ = jax.lax.scan(
+                    body_fn, (x, aux), p_stack, unroll=flags.scan_unroll()
+                )
+            continue
+
+        # The stacked cache rides in the carry, not in xs/ys: each unit writes
+        # its new rows into it in place, so the scan never slices out or
+        # stacks back a whole layer of cache.
+        def cached_body(carry, xs):
+            x_c, aux_c, stack = carry
+            unit_p, layer = xs
+            rows = jax.tree.map(lambda a: attention.LayerRows(a, layer), stack)
+            x_c, nc, aux_c = unit_apply(rs, pattern, unit_p, x_c, rows, aux_c)
+            stack = jax.tree.map(lambda a, n: _write_back(a, n, layer), stack, nc)
+            return (x_c, aux_c, stack), None
+
+        body_fn = jax.checkpoint(cached_body) if rs.remat else cached_body
         with jax.named_scope(scopes.LAYERS):
-            (x, aux), nc_stack = jax.lax.scan(
-                body_fn, (x, aux), xs, unroll=flags.scan_unroll()
+            (x, aux, stack), _ = jax.lax.scan(
+                body_fn, (x, aux, caches[si]),
+                (p_stack, jnp.arange(n_units, dtype=jnp.int32)),
+                unroll=flags.scan_unroll(),
             )
-        if caches is not None:
-            new_caches.append(nc_stack)
+        new_caches.append(stack)
     return x, new_caches, aux
 
 

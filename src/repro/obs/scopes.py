@@ -11,17 +11,16 @@ the layer the operation's time belongs to:
 
 * ``qlinear`` — a quantized projection (code expansion and matmul, or the
   Pallas kernel), whatever module calls it;
-* ``attention`` — scores, softmax and values, and the cache write where XLA
-  does not fuse it into the layer scan's own update;
-* ``kv_write`` — the per-slot KV-cache write, where XLA keeps it separate;
-* ``layers`` — the layer ``lax.scan``: its slicing, stacking and copying
-  of the stacked caches, and what no inner scope claims (norms, residuals,
-  the FFN's activation);
+* ``attention`` — scores, softmax and values, with the slice of the
+  layer's cache they read;
+* ``kv_write`` — the write of a step's KV rows into the stacked cache;
+* ``layers`` — the layer ``lax.scan``: what no inner scope claims (norms,
+  residuals, the FFN's activation, a recurrent state's write-back);
 * ``decode_loop`` — the serving layer's decode loop (``decode_wave``'s
-  ``while_loop``, ``decode_scan``'s scan): the copies that carry the
-  stacked caches from step to step, and the loop body's own work (the
+  ``while_loop``, ``decode_scan``'s scan): the loop body's own work (the
   embedding lookup, the token matrix's write, greedy sampling where XLA
-  does not fuse it into the head);
+  does not fuse it into the head) and any copy XLA inserts to carry the
+  loop's state;
 * ``lm_head`` — the float32 head.
 
 XLA names a fused operation after its root, so a scope placed only around

@@ -203,9 +203,8 @@ def make_decode_wave(model: Model, *, ctx=None, out_cap: int):
             out = out.at[:, t + 1].set(jnp.where(active, nxt[:, 0], -1))
             return (t + 1, nxt, caches, pos + act, out)
 
-        # The loop carries the stacked caches from step to step; the copies
-        # XLA inserts to carry them have no op_name of their own and take
-        # this scope from the loop (``repro.obs.scopes``).
+        # A copy XLA inserts to carry the loop's state has no op_name of its
+        # own and takes this scope from the loop (``repro.obs.scopes``).
         with jax.named_scope(scopes.DECODE_LOOP):
             _, token, caches, pos, out = jax.lax.while_loop(
                 cond, body, (jnp.int32(0), token, caches, pos, out0)

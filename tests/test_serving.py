@@ -60,6 +60,59 @@ def test_prefill_decode_matches_forward(arch):
     )
 
 
+@pytest.mark.parametrize("arch,profile", [
+    ("stablelm-12b", None),              # GQA
+    ("deepseek-v2-lite-16b", None),      # MLA
+    ("gemma2-2b", "serve"),              # int8 KV on global layers, ring on local
+])
+def test_decode_wave_matches_step_loop_bit_for_bit(arch, profile):
+    """The continuous driver's ``while_loop`` program writes each step's rows
+    where the per-step program does: with slots at different depths and one
+    slot inactive, the tokens it emits and the caches it leaves are those of
+    ``make_serve_step`` run step by step, leaf by leaf, bit for bit."""
+    from repro.models.profiles import apply_perf_profile
+    from repro.serve.serving import make_decode_wave, make_serve_step
+
+    cfg = _dropless(get_config(arch, smoke=True))
+    if profile:
+        cfg = apply_perf_profile(cfg, profile, tp=2)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    B, PROMPT, MAX_SEQ, STEPS = 3, 8, 32, 5
+    rng = np.random.default_rng(10)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, PROMPT), dtype=np.int32))
+    pad = jnp.asarray([0, 2, 1], jnp.int32)
+    logits, caches = model.prefill(
+        params, toks, model.init_cache(B, MAX_SEQ, dtype=jnp.float32), pad_len=pad
+    )
+    token = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32)
+    pos = jnp.asarray([PROMPT, PROMPT - 3, PROMPT - 1], jnp.int32)   # three depths
+    active = jnp.asarray([True, False, True])
+
+    wave = make_decode_wave(model, out_cap=STEPS + 1)
+    w_token, w_caches, w_pos, w_out = wave(
+        params, token, jax.tree.map(jnp.copy, caches), pos, pad, active,
+        jnp.int32(STEPS),
+    )
+
+    step = jax.jit(make_serve_step(model))
+    out = [jnp.where(active, token[:, 0], -1)]
+    for _ in range(STEPS):
+        token, caches = step(params, token, caches, pos, pad)
+        out.append(jnp.where(active, token[:, 0], -1))
+        pos = pos + active.astype(jnp.int32)
+
+    np.testing.assert_array_equal(np.asarray(w_out), np.stack(out, axis=1))
+    np.testing.assert_array_equal(np.asarray(w_token), np.asarray(token))
+    np.testing.assert_array_equal(np.asarray(w_pos), np.asarray(pos))
+    flat, tree = jax.tree.flatten(caches)
+    w_flat, w_tree = jax.tree.flatten(w_caches)
+    assert w_tree == tree
+    for got, want in zip(w_flat, flat):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_serve_engine_batched_greedy():
     cfg = get_config("chatglm3-6b", smoke=True)
     model = build_model(cfg)

@@ -12,6 +12,7 @@ compiler.
 import dataclasses
 import importlib.util
 import pathlib
+import re
 import types
 
 import jax
@@ -109,6 +110,101 @@ def test_dequant_decode_step_of_one_stablelm_layer_compiles(one_chip):
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+# The stablelm-12b decode wave at 4 layers, batch 8, 2048 positions: one
+# layer's float32 K (or V) cache, and the segment's stack of four.
+CACHE_SHAPES = ((8, 2048, 8, 160), (4, 8, 2048, 8, 160))
+KV_STACK_BYTES = 2 * 4 * 8 * 2048 * 8 * 160 * 4
+# Temporaries of that wave when the layer scan took the stacked caches in as
+# xs and out as ys (slicing and restacking every layer, every step).
+XS_YS_TEMP_BYTES = 5_279_727_104
+
+_HLO_CALLEES = re.compile(r"\b(?:calls|body|condition|to_apply)=%([\w.\-]+)")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+def _hlo_computations(hlo: str) -> dict:
+    """Compiled HLO text by computation: name -> its lines."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = [line]
+        elif name is not None:
+            comps[name].append(line)
+            if line == "}":
+                name = None
+    return comps
+
+
+def _while_body_computations(hlo: str) -> dict:
+    """Every computation the entry's ``while`` loops run: their bodies and
+    conditions, and what those call (nested loops, fusions) in turn."""
+    comps = _hlo_computations(hlo)
+    entry = next(n for n, lines in comps.items() if lines[0].startswith("ENTRY"))
+    todo = [c for line in comps[entry] if " while(" in line
+            for c in _HLO_CALLEES.findall(line)]
+    seen = {}
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen[name] = comps[name]
+            todo += [c for line in comps[name] for c in _HLO_CALLEES.findall(line)]
+    return seen
+
+
+def test_decode_wave_of_the_stablelm_cell_writes_kv_rows_in_place(one_chip):
+    """The serving cell's decode wave (4 full-width stablelm-12b layers,
+    prepared W4 dequant, batch 8, max_seq 2048, float32 caches): inside its
+    loops no instruction, fused or not, copies, slices out or updates a whole
+    layer's cache or the whole stack; only the rows of the step are written.
+    The temporaries fall by at least the stacked K and V that the xs/ys form
+    of the layer scan kept besides."""
+    from repro.configs import get_config
+    from repro.models.model import build_model
+    from repro.serve.serving import make_decode_wave
+
+    cfg = dataclasses.replace(get_config("stablelm-12b"), n_layers=4)
+    model = build_model(cfg)
+    spec = LutLinearSpec(bw=4, ba=4, mode="dequant")
+    params = jax.eval_shape(
+        lambda key: model.prepare(model.quantize(model.init(key), spec)),
+        jax.random.PRNGKey(0),
+    )
+    caches = jax.eval_shape(lambda: model.init_cache(8, 2048, dtype=jnp.float32))
+    place = lambda tree: jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+    compiled = make_decode_wave(model, out_cap=2048).lower(
+        place(params),
+        _sds((8, 1), jnp.int32, one_chip),
+        place(caches),
+        _sds((8,), jnp.int32, one_chip),
+        _sds((8,), jnp.int32, one_chip),
+        _sds((8,), jnp.bool_, one_chip),
+        _sds((), jnp.int32, one_chip),
+    ).compile()
+
+    loop = _while_body_computations(compiled.as_text())
+    whole_cache = []
+    scatters = 0
+    for lines in loop.values():
+        for line in lines:
+            m = _HLO_INSTR.match(line)
+            if not m:
+                continue
+            name, dtype, dims, op = m.groups()
+            shape = tuple(int(d) for d in dims.split(",") if d and d != "1")
+            if dtype != "f32" or shape not in CACHE_SHAPES:
+                continue
+            scatters += op == "scatter"
+            if op in ("copy", "dynamic-slice", "dynamic-update-slice"):
+                whole_cache.append(f"{op} {name}")
+    assert scatters >= 2, "no scatter of K and V rows into the stacked caches"
+    assert whole_cache == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= XS_YS_TEMP_BYTES - KV_STACK_BYTES
 
 
 # ---------------------------------------------------------------------------
