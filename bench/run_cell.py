@@ -323,8 +323,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     abstract = jax.eval_shape(lambda k: model.quantize(model.init(k), spec),
                               jax.random.PRNGKey(0))
     stamp("imports and device check")
-    w = bench_weights.make_weights(abstract, seed, bw=cfg["bw"],
-                                   d_model=cfg["d_model"])
+    w = bench_weights.make_weights(
+        abstract, seed, bw=cfg["bw"], d_model=cfg["d_model"],
+        rules=getattr(cell.reference, "LEAF_RULES", None))
     jax.block_until_ready(w)
     stamp("weights")
     params = model.prepare(bench_weights.program_tree(abstract, w))
